@@ -216,7 +216,7 @@ def assert_frontend_parity(n_requests: int = 32) -> dict:
     scalar stage and equal ``decision.evaluate`` over the mirror —
     by construction *and* re-checked value-by-value here.
     """
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     from repro.core.decision import Decision, DecisionInputs, evaluate
     from repro.core.posterior import BetaPosterior

@@ -59,7 +59,7 @@ def betaincinv_record(n: int = 4096, block_sweep=(256, 1024, 4096),
     """Parity gate + block_n timing sweep for the betaincinv kernel."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     from scipy import special as sp
 
     from repro.core.betainc import betaincinv as core_betaincinv
@@ -169,7 +169,7 @@ def online_tick_record(n_rows: int = 256, batch: int = 128,
     """Parity gate + block_n timing sweep for the fused tick kernel,
     driven through the real ``OnlineDecisionService`` dispatch."""
     import jax
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     # --- parity first (f64): fused vs default service, same tick
     # stream (requests + settles + drift checks), bitwise everywhere on

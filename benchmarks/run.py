@@ -330,9 +330,14 @@ def smoke() -> dict:
 
 
 def _persist(module_name: str, rows: list[tuple[str, float, str]]) -> None:
+    import jax
+
+    dev = jax.devices()[0]
     out = {
         "module": module_name,
         "host": platform.machine(),
+        "device": {"platform": dev.platform, "kind": dev.device_kind,
+                   "count": len(jax.devices())},
         "python": platform.python_version(),
         "unix_time": int(time.time()),
         "rows": [
@@ -379,6 +384,9 @@ def main(only: list[str] | None = None) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     argv = sys.argv[1:]
     if "--smoke" in argv:
         smoke()

@@ -118,7 +118,7 @@ def dense_paged_parity(*, n_rows: int = 40, resident_rows: int = 8,
     ticks cycling every row force constant LRU spill / fault-in — must
     answer every decision, settle every outcome, and run every drift
     step bitwise-f64 identical to the dense identity-mode service."""
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     from repro.core.online import OnlineDecisionService
 
@@ -202,7 +202,7 @@ def zero_recompile_churn(*, base_rows: int = 256, resident_rows: int = 64,
     multiple times must leave every jit cache exactly where warm-up put
     it: the physical table shape is fixed, so growth is host-only.
     Asserted via compile-cache sizes (the acceptance mechanism)."""
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     from repro.core import online as online_mod
     from repro.core.online import OnlineDecisionService
@@ -289,7 +289,7 @@ def cold_start_curve(*, p_star: float = 0.3, n_warm: int = 64,
     for the pooled-born row vs a fixed-taxonomy-prior twin over the same
     outcome stream — pooled must start strictly tighter and both must
     converge (shrinkage fades under conjugate evidence)."""
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     from repro.core.posterior import BetaPosterior
     from repro.core.store import PosteriorStore
@@ -363,7 +363,7 @@ def store_record(*, logical_rows: int = 1_000_000,
                  write: bool = True) -> dict:
     """Parity gates → zero-recompile churn → cold-start curve → timed
     1M-row register + paged decide churn → BENCH_store.json."""
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     from repro.core.online import OnlineDecisionService
 
@@ -457,7 +457,7 @@ def smoke() -> dict:
     compiles, so tier-1 shares the jit cache), no timing claims, nothing
     written.  The record keeps the full BENCH_store.json shape so schema
     drift breaks tier-1."""
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     from repro.core.online import OnlineDecisionService
 

@@ -243,6 +243,13 @@ def _mt_stack(tenants: int = 8, episodes: int = 200, seed: int = SEED):
     return stack_tenants(lowereds, succs, tenants=names)
 
 
+def _cpu_child_env(pythonpath: str) -> dict:
+    """Environment of a scaling child: it times forced *host* devices, so
+    it is pinned to the CPU and never reaches for an accelerator that the
+    parent process already holds."""
+    return {**os.environ, "PYTHONPATH": pythonpath, "JAX_PLATFORMS": "cpu"}
+
+
 _SCALING_BODY = """
     import os, sys, time, json
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={devices}"
@@ -280,7 +287,7 @@ def multi_tenant_scaling(devices=(1, 2, 4, 8), tenants: int = 8,
             devices=d, paths=paths, tenants=tenants, episodes=episodes))
         proc = subprocess.run(
             [sys.executable, "-c", code], capture_output=True, text=True,
-            timeout=600, env={**os.environ, "PYTHONPATH": paths[1]},
+            timeout=600, env=_cpu_child_env(paths[1]),
         )
         if proc.returncode != 0:
             raise RuntimeError(
@@ -299,7 +306,7 @@ def multi_tenant_record(tenants: int = 8, alphas=DEFAULT_ALPHAS,
     episodes in one jit'd sharded call, bitwise (f64) per-tenant parity
     against T independent ``fleet_replay`` calls, one-call vs per-tenant
     wall times, and the forced-host-device scaling rows."""
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     alphas_arr = np.asarray(alphas)
 
@@ -429,7 +436,7 @@ def episode_sharded_scaling(devices=(1, 2, 4, 8), episodes: int = 1_000_000,
             devices=d, paths=paths, episodes=episodes, segments=segments))
         proc = subprocess.run(
             [sys.executable, "-c", code], capture_output=True, text=True,
-            timeout=1200, env={**os.environ, "PYTHONPATH": paths[1]},
+            timeout=1200, env=_cpu_child_env(paths[1]),
         )
         if proc.returncode != 0:
             raise RuntimeError(
@@ -452,7 +459,7 @@ def episode_sharded_record(episodes: int = 1_000_000,
     *before* any timing is reported, as is the decision-fraction parity
     of the log-axis-sharded §12.1 counterfactual grid the calibration
     reroute rides on."""
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     from repro.core.batch_decision import (
         counterfactual_grid,
@@ -589,7 +596,7 @@ def online_service_record(batch_sizes=(1, 64, 1024), n_rows: int = 64,
     ``decide`` calls per rep.  ``require_speedup`` (full runs) asserts
     the B=max per-decision speedup floor.
     """
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     from repro.core.decision import Decision
     from repro.core.online import OnlineDecisionService
@@ -768,7 +775,7 @@ def beam_record(alphas=DEFAULT_ALPHAS, episodes: int = 200,
     wider beam converts exactly the tail-intent episodes into commits —
     the Pareto rows published here attribute every launched candidate
     (``launched_candidates`` / ``cancelled_candidates``) in USD."""
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     from repro.core import (
         beam_replay,
@@ -907,7 +914,7 @@ def fleet_speedup(alphas=DEFAULT_ALPHAS, episodes: int = 200,
     the timing relies on holds because this workload's decision margins —
     |EV - threshold| ~1e-2 relative — sit orders above both the f32 mean
     error and the ~1e-5 f32 quantile error."""
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     n_runs = len(alphas) * episodes
 

@@ -216,7 +216,7 @@ def _grid_sharded_exec(mesh, axis_name):
         return jax.vmap(one)(lat, cost, mask, rho)
 
     if mesh is not None:
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec
 
         c = PartitionSpec(axis_name)
@@ -225,7 +225,7 @@ def _grid_sharded_exec(mesh, axis_name):
             run, mesh=mesh,
             in_specs=(r, r, c, c, c, r, r, c),
             out_specs=c,
-            check_rep=False,
+            check_vma=False,
         )
     return jax.jit(run)
 

@@ -26,7 +26,7 @@ import statistics
 from typing import Any, Callable, Optional, Sequence
 
 import numpy as np
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from .decision import Decision, decision_threshold, expected_value, implied_lambda
 from .posterior import BetaPosterior

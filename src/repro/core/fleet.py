@@ -944,7 +944,7 @@ def _mt_executable(mesh, axis_name, throttle_every, K, use_lower_bound,
                              success, pred_ok, chunk_P, ep_mask)
 
     if mesh is not None:
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec
 
         t = PartitionSpec(axis_name)
@@ -955,7 +955,7 @@ def _mt_executable(mesh, axis_name, throttle_every, K, use_lower_bound,
             # replicated and rides along under the per-shard vmap
             in_specs=(t, t, t, r, r, t, t, t, t, t),
             out_specs=t,
-            check_rep=False,
+            check_vma=False,
         )
     return jax.jit(run, donate_argnums=(1,) if donate else ())
 
@@ -1199,7 +1199,7 @@ def _seg_executable(mesh, axis_name, throttle_every, K, use_lower_bound):
         return jax.vmap(one)(starts, success, pred_ok, chunk_P, ep_mask)
 
     if mesh is not None:
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec
 
         c = PartitionSpec(axis_name)
@@ -1208,7 +1208,7 @@ def _seg_executable(mesh, axis_name, throttle_every, K, use_lower_bound):
             run, mesh=mesh,
             in_specs=(r, c, r, r, r, r, c, c, c, c),
             out_specs=c,
-            check_rep=False,
+            check_vma=False,
         )
     return jax.jit(run)
 

@@ -189,7 +189,13 @@ def _tick_impl(state, zero, row, logrow, reqs, bconf, bwidth, out_row,
     g = post[ri]
     P_mean = g[:, 0] / (g[:, 0] + g[:, 1])
     if use_lower_bound:
-        P_used = betaincinv(g[:, 0], g[:, 1], rowcfg[ri, 0])
+        # padding lanes gather whatever sits in slot 0; pin them to an
+        # inert Beta(1, 1) so they cannot change how many iterations the
+        # vectorized betainc runs, which would move the real lanes' bits
+        one = jnp.ones_like(P_mean)
+        P_used = betaincinv(jnp.where(valid, g[:, 0], one),
+                            jnp.where(valid, g[:, 1], one),
+                            jnp.where(valid, rowcfg[ri, 0], 0.5 * one))
     else:
         P_used = P_mean
     if use_beam:

@@ -31,9 +31,11 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
-__all__ = ["betaincinv_kernel_call", "betainc_in_kernel", "lbeta_in_kernel"]
+__all__ = ["betaincinv_kernel_call", "betainc_in_kernel", "lbeta_in_kernel",
+           "check_native_dtype"]
 
 # Same fixed Halley budget as core.betainc (the bisection-fallback lanes
 # need the headroom to reach ~1e-16 interval width at float64).
@@ -59,6 +61,16 @@ _LANCZOS = (
     1.5056327351493116e-7,
 )
 _HALF_LOG_2PI = 0.9189385332046727417803297364056176
+
+
+def check_native_dtype(dtype, interpret: bool, kernel: str) -> None:
+    """Refuse f64 operands on a native (Mosaic) lowering: the TPU has no
+    f64 vector unit, and a silent cast down would change the result the
+    caller asked for.  Interpret mode (the CPU parity path) takes f64."""
+    if not interpret and jnp.dtype(dtype) == jnp.float64:
+        raise TypeError(
+            f"{kernel}: Pallas TPU kernels have no float64; got float64 "
+            "operands (turn off jax_enable_x64 or use the XLA path)")
 
 
 def _lgamma(z):
@@ -95,9 +107,8 @@ def _betacf(a, b, x, dt):
     d = 1.0 / d
     h = d
 
-    def body(m, cdh):
+    def body(mf, cdh):
         c, d, h = cdh
-        mf = m.astype(dt) if hasattr(m, "astype") else jnp.asarray(m, dt)
         m2 = 2.0 * mf
         # even step
         aa = mf * (b - mf) * x / ((qam + m2) * (a + m2))
@@ -118,9 +129,12 @@ def _betacf(a, b, x, dt):
         return c, d, h
 
     def step(m, cdh):
-        return body(jnp.asarray(m + 1, dt), cdh)
+        # float, not int, arithmetic on the loop index: an int literal
+        # would lower as i64 under jax_enable_x64
+        return body(m.astype(dt) + 1.0, cdh)
 
-    _, _, h = jax.lax.fori_loop(0, _CF_ITER, step, (c, d, h))
+    _, _, h = jax.lax.fori_loop(np.int32(0), np.int32(_CF_ITER), step,
+                                (c, d, h))
     return h
 
 
@@ -201,7 +215,8 @@ def betaincinv_in_kernel(a, b, q):
         xn = jnp.where(bad, 0.5 * (lo + hi), xn)
         return xn, lo, hi
 
-    x, _, _ = jax.lax.fori_loop(0, _N_ITER, body, (x0, lo0, hi0))
+    x, _, _ = jax.lax.fori_loop(np.int32(0), np.int32(_N_ITER), body,
+                                (x0, lo0, hi0))
     x = jnp.where(q <= 0.0, 0.0, jnp.where(q >= 1.0, 1.0, x))
     valid = (a > 0.0) & (b > 0.0) & (q >= 0.0) & (q <= 1.0)
     return jnp.where(valid, x, jnp.nan)
@@ -228,6 +243,7 @@ def betaincinv_kernel_call(
     """
     n = q.shape[0]
     dtype = jnp.result_type(q.dtype, jnp.float32)
+    check_native_dtype(dtype, interpret, "betaincinv")
     if n == 0:
         return jnp.zeros((0,), dtype)
     a = a.astype(dtype)

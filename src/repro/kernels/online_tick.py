@@ -6,18 +6,19 @@ each reading and writing the same posterior rows.  This kernel fuses all
 three into one launch over ``block_n``-row tiles:
 
     grid = (num_row_blocks,) — sequential on TPU.  Each program settles
-    its rows (a masked elementwise replay of the settle scan, preserving
-    per-row arrival order), gates the requests whose (clamped) row lives
-    in its tile (writing them into revisited (Bp,) output blocks via
-    select — no arithmetic touches another block's values), and runs the
-    trigger-2 breach step on its rows.
+    its rows (a masked elementwise replay of the settle scan over the
+    settles held in SMEM, preserving per-row arrival order), gathers the
+    settled rows of the requests whose (clamped) row lives in its tile
+    into VMEM scratch (by select — no arithmetic touches another tile's
+    values), and runs the trigger-2 breach step on its rows.  The last
+    program runs the D4 gate once over the gathered rows.
 
 Parity tiers (tests/test_kernels.py):
 
 * mean-path ticks (``use_lower_bound=False``) are **bitwise-f64 equal**
   to ``_tick_impl`` — settled posteriors, decisions, drift runs and
   telemetry rows.  The traced-runtime-zero FMA pin survives: ``zero``
-  arrives as a (1,) operand block, so ``x * d + zero`` inside the kernel
+  arrives as an SMEM scalar operand, so ``x * d + zero`` inside the kernel
   contracts (or not) exactly as in the XLA lowering;
 * ``use_lower_bound=True`` gates on the kernel-resident ``betaincinv``
   (see ``betaincinv_pallas`` — ``jax.scipy``'s betainc is a custom call
@@ -45,99 +46,114 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..core.batch_decision import d4_gate
-from .betaincinv_pallas import betaincinv_in_kernel
+from .betaincinv_pallas import betaincinv_in_kernel, check_native_dtype
 
 __all__ = ["online_tick_kernel_call"]
 
 
 def _online_tick_kernel(
-    # replicated operands
-    zero_ref, cn_ref, srow_ref, alpha_ref, lam_ref, lat_ref, itok_ref,
-    otok_ref, iprice_ref, oprice_ref, orow_ref, ox_ref,
-    # row-tiled operands
+    # scalar operands (SMEM)
+    zero_ref, cn_ref, orow_ref, ox_ref,
+    # request columns, (Bk, 1)
+    srow_ref, alpha_ref, lam_ref, lat_ref, itok_ref, otok_ref, iprice_ref,
+    oprice_ref,
+    # row-tiled table columns, (1, block_n)
     a_ref, b_ref, gam_ref, disc_ref, floor_ref, en_ref, run_ref,
     # row-tiled outputs
     a_out, b_out, en_out, run_out, trig_out,
-    # revisited (Bp,) request outputs
+    # revisited (Bk, 1) request outputs, written at the last program
     pused_out, pmean_out, ev_out, thr_out, cspec_out, lval_out,
     flag_out, enreq_out,
+    # (Bk, 1) scratch: the gathered request rows, filled tile by tile
+    ga_acc, gb_acc, ggam_acc, gen_acc,
     *, use_lower_bound: bool, check_drift: bool,
 ):
     i = pl.program_id(0)
-    block_n = a_ref.shape[0]
+    block_n = a_ref.shape[1]
     zero = zero_ref[0]
     base = i * block_n
-    lane = jax.lax.broadcasted_iota(jnp.int32, (block_n, 1), 0)[:, 0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, block_n), 1)
 
     @pl.when(i == 0)
     def _init():
-        for ref in (pused_out, pmean_out, ev_out, thr_out, cspec_out,
-                    lval_out, flag_out, enreq_out):
+        for ref in (ga_acc, gb_acc, ggam_acc, gen_acc):
             ref[...] = jnp.zeros_like(ref)
 
     a = a_ref[...]
     b = b_ref[...]
     disc = disc_ref[...]
     gam = gam_ref[...]
+    dt = a.dtype
 
     # ---- 1. settle: sequential masked replay of the settle scan.  Each
-    # entry updates exactly one lane with the same ``(a*d + zero) + x``
-    # recurrence as ``_tick_impl``; entries hitting other tiles (or the
-    # -1 sentinel) are full-width no-ops, so per-row arrival order is
-    # preserved and cross-tile order is irrelevant.
-    S = orow_ref.shape[0]
-    if S:
-        orow = orow_ref[...]
-        ox = ox_ref[...]
+    # entry (a scalar read from SMEM) updates exactly one lane with the
+    # same ``(a*d + zero) + x`` recurrence as ``_tick_impl``; entries
+    # hitting other tiles (or the -1 sentinel) are full-width no-ops, so
+    # per-row arrival order is preserved and cross-tile order is
+    # irrelevant.
+    def settle_step(s, ab):
+        a, b = ab
+        r = orow_ref[s]
+        x = ox_ref[s]
+        m = (r >= 0) & (lane == r - base)
+        a2 = (a * disc + zero) + x
+        b2 = (b * disc + zero) + (1.0 - x)
+        return jnp.where(m, a2, a), jnp.where(m, b2, b)
 
-        def settle_step(s, ab):
-            a, b = ab
-            r = jax.lax.dynamic_index_in_dim(orow, s, keepdims=False)
-            x = jax.lax.dynamic_index_in_dim(ox, s, keepdims=False)
-            rl = r - base
-            m = (r >= 0) & (lane == rl)
-            a2 = (a * disc + zero) + x
-            b2 = (b * disc + zero) + (1.0 - x)
-            return jnp.where(m, a2, a), jnp.where(m, b2, b)
+    a, b = jax.lax.fori_loop(np.int32(0), np.int32(orow_ref.shape[0]),
+                             settle_step, (a, b))
 
-        a, b = jax.lax.fori_loop(0, S, settle_step, (a, b))
-
-    # ---- 2. D4 gate for the requests this tile owns (clamped row in
-    # [base, base + block_n)).  The posterior gather is a one-hot select
-    # + sum — every addend but the target lane is an exact 0.0, so the
-    # gathered (a, b) are bitwise the table rows.
+    # ---- 2a. gather the settled rows of the requests this tile owns
+    # (clamped row in [base, base + block_n)).  A one-hot select + lane
+    # sum: every addend but the target lane is an exact 0.0, so the
+    # gathered values are bitwise the table rows.
     srow = srow_ref[...]
-    ri = jnp.maximum(srow, 0)
-    rl = ri - base
+    rl = jnp.maximum(srow, 0) - base
     own = (rl >= 0) & (rl < block_n)
-    sel = (lane[:, None] == rl[None, :]) & own[None, :]
-    ga = jnp.where(sel, a[:, None], 0.0).sum(0)
-    gb = jnp.where(sel, b[:, None], 0.0).sum(0)
-    gen = jnp.where(sel, en_ref[...][:, None], 0).sum(0)
-    P_mean = ga / (ga + gb)
-    if use_lower_bound:
-        ggam = jnp.where(sel, gam[:, None], 0.0).sum(0)
-        P_used = betaincinv_in_kernel(ga, gb, ggam)
-    else:
-        P_used = P_mean
-    EV, thr, flag, C_spec, L_value = d4_gate(
-        P_used, alpha_ref[...], lam_ref[...], lat_ref[...], itok_ref[...],
-        otok_ref[...], iprice_ref[...], oprice_ref[...], zero)
+    sel = lane == rl                                    # (Bk, block_n)
 
-    def wr(ref, val):
-        ref[...] = jnp.where(own, val.astype(ref.dtype), ref[...])
+    def gather(acc, col):
+        got = jnp.where(sel, col, 0.0).sum(1, keepdims=True)
+        acc[...] = jnp.where(own, got, acc[...])
 
-    wr(pused_out, P_used)
-    wr(pmean_out, P_mean)
-    wr(ev_out, EV)
-    wr(thr_out, thr)
-    wr(cspec_out, C_spec)
-    wr(lval_out, L_value)
-    wr(flag_out, flag.astype(jnp.int32))
-    wr(enreq_out, (gen > 0).astype(jnp.int32))
+    gather(ga_acc, a)
+    gather(gb_acc, b)
+    gather(ggam_acc, gam)
+    one = jnp.ones((), dt)
+    nil = jnp.zeros((), dt)
+    gather(gen_acc, jnp.where(en_ref[...] > 0, one, nil))
+
+    # ---- 2b. D4 gate, once, after the last tile has been gathered.
+    @pl.when(i == pl.num_programs(0) - 1)
+    def _gate():
+        ga = ga_acc[...]
+        gb = gb_acc[...]
+        P_mean = ga / (ga + gb)
+        if use_lower_bound:
+            # padding lanes inverted as an inert Beta(1, 1), as _tick_impl
+            valid = srow_ref[...] >= 0
+            P_used = betaincinv_in_kernel(
+                jnp.where(valid, ga, one), jnp.where(valid, gb, one),
+                jnp.where(valid, ggam_acc[...], 0.5 * one))
+        else:
+            P_used = P_mean
+        EV, thr, flag, C_spec, L_value = d4_gate(
+            P_used, alpha_ref[...], lam_ref[...], lat_ref[...],
+            itok_ref[...], otok_ref[...], iprice_ref[...], oprice_ref[...],
+            zero)
+        pused_out[...] = P_used
+        pmean_out[...] = P_mean
+        ev_out[...] = EV
+        thr_out[...] = thr
+        cspec_out[...] = C_spec
+        lval_out[...] = L_value
+        flag_out[...] = flag.astype(jnp.int32)
+        enreq_out[...] = (gen_acc[...] > 0).astype(jnp.int32)
 
     # ---- 3. trigger-2 drift over this tile's rows (post-settle table,
     # touched = any valid request landed on the row — the same mask
@@ -145,14 +161,17 @@ def _online_tick_kernel(
     en = en_ref[...]
     run = run_ref[...]
     if check_drift:
-        valid = srow >= 0
-        touched = (sel & valid[None, :]).any(1)
+        hit = sel & (srow >= 0)
+        touched = jnp.where(hit, one, nil).sum(0, keepdims=True) > 0
         P_low = betaincinv_in_kernel(a, b, gam)
         breached = touched & (P_low < floor_ref[...])
-        run = jnp.where(touched, jnp.where(breached, run + 1, 0), run)
+        # int literals stay out of int selects: under jax_enable_x64
+        # they would lower as i64, which Mosaic refuses
+        run0 = jnp.zeros_like(run)
+        run = jnp.where(touched, jnp.where(breached, run + 1, run0), run)
         triggered = touched & (run >= cn_ref[0])
         en = ((en > 0) & ~triggered).astype(jnp.int32)
-        run = jnp.where(triggered, 0, run)
+        run = jnp.where(triggered, run0, run)
         trig_out[...] = triggered.astype(jnp.int32)
     else:
         trig_out[...] = jnp.zeros_like(trig_out)
@@ -191,6 +210,7 @@ def online_tick_kernel_call(
     N = post.shape[0]
     Bp = row.shape[0]
     dt = post.dtype
+    check_native_dtype(dt, interpret, "online_tick")
     block_n = min(block_n, max(N, 1))
     nb = -(-N // block_n)
     pad_n = nb * block_n - N
@@ -212,10 +232,14 @@ def online_tick_kernel_call(
 
     # Bp = 0 ticks (settle-only / drift-only): pad one sentinel request
     # slot so the revisited output blocks stay non-empty; sliced off.
+    # S = 0 likewise gets one -1 sentinel settle (a no-op step).
     Bk = max(Bp, 1)
     if Bp == 0:
         row = jnp.full((1,), -1, jnp.int32)
         reqs = jnp.zeros((1, 7), dt)
+    if out_row.shape[0] == 0:
+        out_row = jnp.full((1,), -1, jnp.int32)
+        out_x = jnp.zeros((1,), dt)
 
     zero1 = jnp.reshape(zero, (1,)).astype(dt)
     cn1 = jnp.reshape(jnp.asarray(consecutive_n, jnp.int32), (1,))
@@ -225,40 +249,42 @@ def online_tick_kernel_call(
         use_lower_bound=bool(use_lower_bound),
         check_drift=bool(check_drift),
     )
-    rep = pl.BlockSpec((Bk,), lambda i: (0,))
-    tile = pl.BlockSpec((block_n,), lambda i: (i,))
+    # Table columns ride as (1, Np) rows tiled along lanes; request
+    # columns as (Bk, 1) so the one-hot gather is a (Bk, block_n)
+    # broadcast Mosaic lays out without reshapes.
+    # index maps return int32 zeros: a Python 0 would lower as i64 under
+    # jax_enable_x64
+    z = np.int32(0)
+
+    def smem(n):
+        return pl.BlockSpec((n,), lambda i: (z,), memory_space=pltpu.SMEM)
+
+    col = pl.BlockSpec((Bk, 1), lambda i: (z, z))
+    tile = pl.BlockSpec((1, block_n), lambda i: (z, i))
     Np = nb * block_n
     outs = pl.pallas_call(
         kernel,
         grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((1,), lambda i: (0,)),          # zero
-            pl.BlockSpec((1,), lambda i: (0,)),          # consecutive_n
-            rep, rep, rep, rep, rep, rep, rep, rep,      # row + req cols
-            pl.BlockSpec((max(out_row.shape[0], 1),), lambda i: (0,)),
-            pl.BlockSpec((max(out_row.shape[0], 1),), lambda i: (0,)),
-            tile, tile, tile, tile, tile, tile, tile,    # table columns
-        ],
-        out_specs=(
-            [tile] * 5
-            + [pl.BlockSpec((Bk,), lambda i: (0,))] * 8
-        ),
+        in_specs=([smem(1), smem(1), smem(out_row.shape[0]),
+                   smem(out_row.shape[0])] + [col] * 8 + [tile] * 7),
+        out_specs=[tile] * 5 + [col] * 8,
         out_shape=(
-            [jax.ShapeDtypeStruct((Np,), dt)] * 2
-            + [jax.ShapeDtypeStruct((Np,), jnp.int32)] * 3
-            + [jax.ShapeDtypeStruct((Bk,), dt)] * 6
-            + [jax.ShapeDtypeStruct((Bk,), jnp.int32)] * 2
+            [jax.ShapeDtypeStruct((1, Np), dt)] * 2
+            + [jax.ShapeDtypeStruct((1, Np), jnp.int32)] * 3
+            + [jax.ShapeDtypeStruct((Bk, 1), dt)] * 6
+            + [jax.ShapeDtypeStruct((Bk, 1), jnp.int32)] * 2
         ),
+        scratch_shapes=[pltpu.VMEM((Bk, 1), dt)] * 4,
         interpret=interpret,
     )(
-        zero1, cn1, row, reqs[:, 0], reqs[:, 1], reqs[:, 2], reqs[:, 3],
-        reqs[:, 4], reqs[:, 5], reqs[:, 6],
-        (out_row if out_row.shape[0] else jnp.full((1,), -1, jnp.int32)),
-        (out_x if out_row.shape[0] else jnp.zeros((1,), dt)),
-        a, b, gam, disc, floor, en, run,
+        zero1, cn1, out_row.astype(jnp.int32), out_x.astype(dt),
+        *(v.reshape(Bk, 1)
+          for v in (row.astype(jnp.int32), *reqs.astype(dt).T)),
+        *(v.reshape(1, Np) for v in (a, b, gam, disc, floor, en, run)),
     )
     (a2, b2, en2, run2, trig,
-     pused, pmean, ev, thr, cspec, lval, flagv, enreq) = outs
+     pused, pmean, ev, thr, cspec, lval, flagv, enreq) = (
+        o.reshape(-1) for o in outs)
     post2 = jnp.stack([a2[:N], b2[:N]], axis=1)
     flags2 = jnp.stack([en2[:N], run2[:N]], axis=1)
     return (post2, flags2, pused[:Bp], pmean[:Bp], ev[:Bp], thr[:Bp],

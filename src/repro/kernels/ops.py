@@ -1,15 +1,15 @@
 """jit'd public wrappers for the Pallas kernels.
 
-Backend dispatch: on TPU the kernels compile natively; elsewhere they run
-under interpret=True (Python evaluation of the kernel body — correctness
-validation on CPU).  ``flash_attention`` exposes a custom_vjp whose
-backward is the rematerialized reference (fused bwd kernel is future
-work); the scan kernels are forward-only ops used by serving paths.
+Backend dispatch: on TPU the kernels compile natively; on the CPU they
+run under interpret=True (Python evaluation of the kernel body —
+correctness validation only); any other backend raises.
+``flash_attention`` exposes a custom_vjp whose backward is the
+rematerialized reference (fused bwd kernel is future work); the scan
+kernels are forward-only ops used by serving paths.
 """
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
@@ -35,29 +35,28 @@ __all__ = [
     "on_tpu",
 ]
 
-# Explicit override for the interpret/native switch.  Unset (the
-# default) -> backend autodetection: native lowering on TPU, interpret
-# elsewhere.  "1"/"true"/"yes"/"interpret" -> force interpret; any other
-# non-empty value -> force native.
-_INTERPRET_ENV = "REPRO_PALLAS_INTERPRET"
-
-
 def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
 def _interpret() -> bool:
-    """Resolve the Pallas interpret flag: env override first, then
-    backend autodetection (native iff the default backend is TPU).
+    """The Pallas interpret flag, from the default backend: interpret
+    mode on the CPU, native Mosaic lowering on the TPU.  Any other
+    backend raises: a kernel never drops to the interpreter where a
+    device was expected.
 
     Resolved OUTSIDE jit by the ops below and passed as a static arg, so
-    flipping the env var between calls is honored rather than baked into
-    the first trace.
+    a backend change between calls is honored rather than baked into the
+    first trace.
     """
-    env = os.environ.get(_INTERPRET_ENV, "").strip().lower()
-    if env:
-        return env in ("1", "true", "yes", "interpret")
-    return not on_tpu()
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(
+        f"no Pallas lowering for backend {backend!r} (cpu: interpret, "
+        "tpu: native)")
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))

@@ -20,8 +20,11 @@ Per row i and cell (a, l):
     waste_sum += spec * (1 - P_i) * cost_i * rho      (§9.3 expected waste)
 
 Padded rows are encoded as (P=0, lat=0, cost=1) so they never speculate
-and contribute zero to every sum; padded alpha cells use alpha=1 and
-padded lambda cells lam=0, and are sliced off by the wrapper.
+and contribute zero to every sum; padded lambda cells use lam=0 and are
+sliced off by the wrapper.  Layout: the log rows ride along lanes as
+(1, block_n) tiles, lambdas down sublanes as an (Lp, 1) column, and the
+alphas are SMEM scalars looped over in the kernel, so every broadcast is
+a plain (Lp, block_n) one that Mosaic lays out without reshapes.
 
 Validated under interpret=True on CPU against ``ref.reference_replay_grid``
 (and transitively against ``batch_decision.counterfactual_grid``).
@@ -34,6 +37,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .betaincinv_pallas import check_native_dtype
 
 __all__ = ["replay_grid_kernel_call", "replay_grid_summary"]
 
@@ -48,23 +54,31 @@ def _replay_grid_kernel(alpha_ref, lam_ref, p_ref, lat_ref, cost_ref,
         lat_o_ref[...] = jnp.zeros_like(lat_o_ref)
         waste_o_ref[...] = jnp.zeros_like(waste_o_ref)
 
-    P = p_ref[...]        # (bn,)
-    lat = lat_ref[...]    # (bn,)
-    cost = cost_ref[...]  # (bn,)
-    alphas = alpha_ref[...]  # (A,)
-    lams = lam_ref[...]      # (L,)
+    P = p_ref[...]        # (1, bn)
+    lat = lat_ref[...]    # (1, bn)
+    cost = cost_ref[...]  # (1, bn)
+    lams = lam_ref[...]   # (Lp, 1)
+    one = jnp.ones((), P.dtype)
+    nil = jnp.zeros((), P.dtype)
 
-    gain = (P * lat)[None, :] * lams[:, None]          # (L, bn)
-    lose = (1.0 - P) * cost                            # (bn,)
-    ev = gain[None, :, :] - lose[None, None, :]        # (1, L, bn)
-    thr = (1.0 - alphas)[:, None, None] * cost[None, None, :]  # (A, 1, bn)
-    spec = ev >= thr                                   # (A, L, bn)
+    gain = lams * (P * lat)                            # (Lp, bn)
+    lose = (1.0 - P) * cost                            # (1, bn)
+    ev = gain - lose                                   # (Lp, bn)
+    lat_spec = lat * (1.0 - P)                         # (1, bn)
 
-    count_ref[...] += spec.sum(-1).astype(count_ref.dtype)
-    exp_lat = jnp.where(spec, (lat * (1.0 - P))[None, None, :],
-                        lat[None, None, :])
-    lat_o_ref[...] += exp_lat.sum(-1)
-    waste_o_ref[...] += (spec * lose[None, None, :]).sum(-1) * rho
+    # one (Lp, bn) pass per alpha; alphas are SMEM scalars and each
+    # alpha's per-lambda sums land in its own (Lp, 1) output slab
+    def per_alpha(k, carry):
+        thr = (1.0 - alpha_ref[k]) * cost              # (1, bn)
+        spec = ev >= thr                               # (Lp, bn)
+        count_ref[k] += jnp.where(spec, one, nil).sum(1, keepdims=True)
+        lat_o_ref[k] += jnp.where(spec, lat_spec, lat).sum(1, keepdims=True)
+        waste_o_ref[k] += jnp.where(spec, lose, nil).sum(
+            1, keepdims=True) * rho
+        return carry
+
+    jax.lax.fori_loop(np.int32(0), np.int32(alpha_ref.shape[0]), per_alpha,
+                      0)
 
 
 def replay_grid_kernel_call(
@@ -84,6 +98,7 @@ def replay_grid_kernel_call(
     A = alphas.shape[0]
     L = lambdas.shape[0]
     dtype = jnp.result_type(P.dtype, jnp.float32)
+    check_native_dtype(dtype, interpret, "replay_grid")
     if n == 0:
         zeros = jnp.zeros((A, L), dtype)
         return zeros, zeros, zeros
@@ -100,37 +115,28 @@ def replay_grid_kernel_call(
         lat = jnp.pad(lat, (0, pad_n))
         cost = jnp.pad(cost, (0, pad_n), constant_values=1.0)
 
-    # pad the grid axes toward TPU tile shape (harmless under interpret)
-    Ap = -(-A // 8) * 8
-    Lp = -(-L // 128) * 128
-    alphas_p = jnp.pad(alphas.astype(dtype), (0, Ap - A),
-                       constant_values=1.0)
-    lambdas_p = jnp.pad(lambdas.astype(dtype), (0, Lp - L))
+    # the lambda axis runs down sublanes: pad it to the 8-row tile
+    Lp = -(-L // 8) * 8
+    lambdas_p = jnp.pad(lambdas.astype(dtype), (0, Lp - L)).reshape(Lp, 1)
 
     kernel = functools.partial(_replay_grid_kernel, rho=float(rho))
+    z = np.int32(0)       # a Python 0 would lower as i64 under x64
+    row = pl.BlockSpec((1, block_n), lambda i: (z, i))
+    acc = pl.BlockSpec((A, Lp, 1), lambda i: (z, z, z))
     count, lat_sum, waste_sum = pl.pallas_call(
         kernel,
         grid=(nb,),
         in_specs=[
-            pl.BlockSpec((Ap,), lambda i: (0,)),
-            pl.BlockSpec((Lp,), lambda i: (0,)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
+            pl.BlockSpec((A,), lambda i: (z,), memory_space=pltpu.SMEM),
+            pl.BlockSpec((Lp, 1), lambda i: (z, z)),
+            row, row, row,
         ],
-        out_specs=[
-            pl.BlockSpec((Ap, Lp), lambda i: (0, 0)),
-            pl.BlockSpec((Ap, Lp), lambda i: (0, 0)),
-            pl.BlockSpec((Ap, Lp), lambda i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((Ap, Lp), dtype),
-            jax.ShapeDtypeStruct((Ap, Lp), dtype),
-            jax.ShapeDtypeStruct((Ap, Lp), dtype),
-        ],
+        out_specs=[acc, acc, acc],
+        out_shape=[jax.ShapeDtypeStruct((A, Lp, 1), dtype)] * 3,
         interpret=interpret,
-    )(alphas_p, lambdas_p, P, lat, cost)
-    return count[:A, :L], lat_sum[:A, :L], waste_sum[:A, :L]
+    )(alphas.astype(dtype), lambdas_p, P.reshape(1, -1), lat.reshape(1, -1),
+      cost.reshape(1, -1))
+    return (count[:, :L, 0], lat_sum[:, :L, 0], waste_sum[:, :L, 0])
 
 
 def replay_grid_summary(

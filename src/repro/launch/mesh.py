@@ -60,6 +60,11 @@ def make_fleet_mesh(n: int | None = None, *, axis: str = "fleet"):
     axis of ``repro.core.fleet.episode_sharded_replay`` (one tenant's
     million-episode log as C independent scan segments) — both
     shard_map'd via ``sharding.rules.fleet_axis_spec``.  Defaults to
-    every visible (real or XLA_FLAGS-forced) device."""
+    every visible (real or XLA_FLAGS-forced) device.
+
+    The axis is ``Auto``: the engines gather rows by dynamic index from
+    tables sharded on it (``jax.make_mesh`` otherwise makes it
+    ``Explicit``, where such gathers are sharding-type errors)."""
     n = len(jax.devices()) if n is None else n
-    return jax.make_mesh((n,), (axis,))
+    return jax.make_mesh((n,), (axis,),
+                         axis_types=(jax.sharding.AxisType.Auto,))

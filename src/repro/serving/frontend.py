@@ -326,6 +326,9 @@ class FrontendTicket:
 
 _CONSERVATIVE = FrontendResult(decision=Decision.WAIT, source="conservative")
 
+# longest tick-exception message kept in an event's ``detail``
+_DETAIL_CHARS = 500
+
 
 class ServingFrontend:
     """Request-accumulation layer in front of an ``OnlineDecisionService``.
@@ -381,6 +384,10 @@ class ServingFrontend:
         # after the last refresh fall through to a lazy per-row
         # ``rows_snapshot`` read (see _mirror_row).
         self._snapshot = np.asarray(service.posterior_snapshot(), np.float64)
+        # "<Type>: <message>" of the newest tick exception (None while
+        # every tick has succeeded); the same text is each fault event's
+        # ``detail``
+        self.last_tick_error: Optional[str] = None
         self.stats = {
             "submitted": 0, "service": 0, "scalar": 0, "conservative": 0,
             "shed": 0, "tick_faults": 0, "deadline_ticks": 0,
@@ -595,6 +602,7 @@ class ServingFrontend:
             row, reqs, batch=B, out_row=out_row, out_x=out_x,
             check_drift=cfg.check_drift)
         fault_kind: Optional[str] = None
+        fault_detail = ""
         decisions = None
         try:
             if cfg.tick_timeout_s is not None:
@@ -603,8 +611,12 @@ class ServingFrontend:
                 decisions = tick()
         except SpeculationTimeout:
             fault_kind = "timeout"
-        except Exception:
+        except Exception as e:
+            # the chain still answers every request, but why the tick
+            # failed (a compile error, a device fault) is kept, not lost
             fault_kind = "exception"
+            fault_detail = f"{type(e).__name__}: {e}"[:_DETAIL_CHARS]
+            self.last_tick_error = fault_detail
 
         self._ticks += 1
         if decisions is None:
@@ -618,7 +630,8 @@ class ServingFrontend:
             self.stats["tick_faults"] += 1
             keys = {t.request.key for t in batch}
             for t in batch:
-                self._emit(t.request, fault_kind, t.request.C_spec_usd)
+                self._emit(t.request, fault_kind, t.request.C_spec_usd,
+                           detail=fault_detail)
             for key in keys:
                 self.breaker.record_failure(key)
             for t in batch:

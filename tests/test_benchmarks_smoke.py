@@ -234,11 +234,9 @@ def test_kernels_smoke_gate_parity_before_timing():
     tick = rec["online_tick"]
     assert tick["parity"]["mean_path_bitwise_f64"] is True
     assert tick["parity"]["lower_bound_max_rel"] <= 1e-9
-    # absent an explicit env override the kernels run in interpret mode
-    # off-TPU (Mosaic only lowers on TPU) and the record must say so
-    import os
-    if not os.environ.get("REPRO_PALLAS_INTERPRET"):
-        assert rec["interpret"] == (rec["backend"] != "tpu")
+    # the kernels run in interpret mode on the CPU and natively on the
+    # TPU (Mosaic only lowers there), and the record must say so
+    assert rec["interpret"] == (rec["backend"] != "tpu")
     # tiny shapes: the smoke record never masquerades as the real one
     assert bii["n"] < 1024 and tick["rows"] < 64
 

@@ -236,7 +236,7 @@ class TestOfflineReplayShardedReroute:
         rows (it used to broadcast-crash for segments > 1), and a mesh
         without the fleet axis must fall back to the unsharded
         executable instead of raising KeyError."""
-        from jax.experimental import enable_x64
+        from jax import enable_x64
 
         from repro.core.batch_decision import (
             counterfactual_grid,

@@ -11,7 +11,7 @@ import dataclasses
 
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from repro.core import (
     Edge,

@@ -236,6 +236,52 @@ class TestFrontendFaultMatrix:
         assert kinds["breaker_open"] == 1
         assert kinds["fallback_scalar"] == 4
 
+    def test_tick_exception_message_reaches_resilience_log(self):
+        """A failing tick still degrades every request down the chain,
+        but the exception's type and message are kept: on each
+        ``exception`` event's detail and as ``last_tick_error``."""
+        from repro.serving.faults import FaultInjector, FaultPlan, FaultyService
+        from repro.serving.frontend import FrontendConfig, ServingFrontend
+
+        svc = _frontend_service(n_tenants=1, edges=1)
+        inj = FaultInjector(FaultPlan(raise_calls=frozenset({0})))
+        fe = ServingFrontend(FaultyService(svc, inj),
+                             FrontendConfig(max_batch=2), clock=_Clock(),
+                             autostart=False)
+        assert fe.last_tick_error is None
+        tk = fe.submit(_fe_req(0, "t0", ("u0", "v0")))
+        fe.pump()
+        assert tk.result(0).source == "scalar"
+        want = "InjectedFault: injected fault at call 0"
+        assert fe.last_tick_error == want
+        exc = [e for e in fe.resilience if e.kind == "exception"]
+        assert [e.detail for e in exc] == [want]
+        # the next, healthy tick leaves the newest error in place
+        if tk.result(0).speculate:
+            tk.release()
+        tk = fe.submit(_fe_req(0, "t0", ("u0", "v0")))
+        fe.pump()
+        assert tk.result(0).source == "service"
+        assert fe.last_tick_error == want
+
+        class _Raising:
+            def __init__(self, inner):
+                self._inner = inner
+
+            def __getattr__(self, name):
+                return getattr(self._inner, name)
+
+            def tick_packed(self, *a, **k):
+                raise ValueError("x" * 5000)
+
+        fe2 = ServingFrontend(_Raising(svc), FrontendConfig(max_batch=2),
+                              clock=_Clock(), autostart=False)
+        fe2.submit(_fe_req(0, "t0", ("u0", "v0")))
+        fe2.pump()
+        assert fe2.last_tick_error.startswith("ValueError: xxx")
+        assert len(fe2.last_tick_error) == 500
+        assert fe2.stats["tick_faults"] == 1
+
     def test_half_open_probe_recovers_service_path(self):
         from repro.serving.faults import FaultInjector, FaultPlan, FaultyService
         from repro.serving.frontend import (
@@ -323,7 +369,7 @@ class TestFrontendFaultMatrix:
         """Both degraded stages answer with exactly decision.evaluate:
         tick faults (stage 2 via exception) and breaker-open (stage 2 via
         admission) under enable_x64 — bitwise EV/threshold/P."""
-        from jax.experimental import enable_x64
+        from jax import enable_x64
 
         from repro.core.decision import DecisionInputs, evaluate
         from repro.core.posterior import BetaPosterior

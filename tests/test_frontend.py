@@ -204,7 +204,7 @@ class TestFrontendChain:
         assert fe.stats["shed"] == 2
 
     def test_breaker_open_degrades_to_scalar_bitwise(self):
-        from jax.experimental import enable_x64
+        from jax import enable_x64
 
         from repro.core.decision import DecisionInputs, evaluate
 

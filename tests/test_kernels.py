@@ -4,7 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 pytestmark = pytest.mark.slow  # interpret=True Pallas sweeps
 
@@ -480,42 +480,75 @@ class TestOnlineTickKernel:
 
 
 class TestInterpretResolution:
-    """kernels.ops._interpret(): the env var is an explicit override;
-    unset, backend autodetection decides (native iff TPU) — the
-    regression pin for the resolution order, applied uniformly to
-    replay_grid and the two new kernel ops (all of which resolve the
-    flag OUTSIDE jit and pass it as a static arg)."""
+    """kernels.ops._interpret(): the default backend alone decides —
+    cpu -> interpret, tpu -> native, anything else raises — with no
+    environment override.  The regression pin for the rule, applied
+    uniformly to replay_grid and the two kernel ops (all of which resolve
+    the flag OUTSIDE jit and pass it as a static arg)."""
 
     def test_resolution_order(self, monkeypatch):
         from repro.kernels import ops
 
-        monkeypatch.delenv(ops._INTERPRET_ENV, raising=False)
-        assert ops._interpret() == (not ops.on_tpu())
-        for v in ("1", "true", "YES", " interpret "):
-            monkeypatch.setenv(ops._INTERPRET_ENV, v)
-            assert ops._interpret() is True, v
-        for v in ("0", "false", "native", "no"):
-            monkeypatch.setenv(ops._INTERPRET_ENV, v)
-            assert ops._interpret() is False, v
-        # empty string == unset: autodetection, not forced-native
-        monkeypatch.setenv(ops._INTERPRET_ENV, "")
-        assert ops._interpret() == (not ops.on_tpu())
+        assert ops._interpret() is True          # the CPU test backend
+        monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+        assert ops._interpret() is False
+        assert ops.on_tpu()
+        for other in ("gpu", "cuda", "rocm", "METAL"):
+            monkeypatch.setattr(ops.jax, "default_backend",
+                                lambda other=other: other)
+            with pytest.raises(RuntimeError, match="no Pallas lowering"):
+                ops._interpret()
 
     def test_flag_not_baked_into_trace(self, monkeypatch):
-        """Flipping the env var between calls must be honored: the jitted
-        ops take `interpret` as a static arg resolved per call, so the
-        override cannot be frozen into the first executable."""
+        """The rule is re-read on every call: the jitted ops take
+        `interpret` as a static arg resolved per call, so a backend with
+        no lowering raises even after a CPU call traced and cached the
+        interpret executable, and the CPU answer is unchanged after."""
         from repro.kernels import ops
 
         with enable_x64():
             a = jnp.asarray(np.array([2.0, 0.5]))
             b = jnp.asarray(np.array([3.0, 0.5]))
             q = jnp.asarray(np.array([0.1, 0.5]))
-            monkeypatch.setenv(ops._INTERPRET_ENV, "interpret")
             first = np.asarray(ops.betaincinv_op(a, b, q))
-            # still-interpret after a flip back and forth; on CPU the
-            # native branch cannot lower, so resolution landing on
-            # interpret both times IS the observable contract
-            monkeypatch.setenv(ops._INTERPRET_ENV, "1")
+            monkeypatch.setattr(ops.jax, "default_backend", lambda: "gpu")
+            with pytest.raises(RuntimeError, match="no Pallas lowering"):
+                ops.betaincinv_op(a, b, q)
+            monkeypatch.undo()
             second = np.asarray(ops.betaincinv_op(a, b, q))
             np.testing.assert_array_equal(first, second)
+
+
+class TestNativeDtypeGuard:
+    """A native (TPU) lowering never sees f64: each kernel raises a clear
+    error on f64 operands rather than casting them down, while the
+    interpret path keeps taking f64 (the CPU parity tier)."""
+
+    @staticmethod
+    def _call(kernel, dt, interpret):
+        n = 8
+        x = jnp.full((n,), 0.5, dt)
+        if kernel == "betaincinv":
+            from repro.kernels.betaincinv_pallas import betaincinv_kernel_call
+            return betaincinv_kernel_call(x + 1.0, x + 2.0, x,
+                                          interpret=interpret)
+        if kernel == "replay_grid":
+            from repro.kernels.replay_grid import replay_grid_kernel_call
+            return replay_grid_kernel_call(x, x, x, x[:3], x[:2],
+                                           interpret=interpret)
+        from repro.kernels.online_tick import online_tick_kernel_call
+        return online_tick_kernel_call(
+            jnp.ones((n, 2), dt), jnp.ones((n, 3), dt),
+            jnp.ones((n, 2), jnp.int32), jnp.zeros((), dt),
+            jnp.arange(4, dtype=jnp.int32), jnp.ones((4, 7), dt),
+            jnp.full((2,), -1, jnp.int32), jnp.zeros((2,), dt),
+            np.int32(3), interpret=interpret)
+
+    @pytest.mark.parametrize("kernel",
+                             ["betaincinv", "replay_grid", "online_tick"])
+    def test_f64_native_raises_interpret_runs(self, kernel):
+        with enable_x64():
+            with pytest.raises(TypeError, match="float64"):
+                self._call(kernel, jnp.float64, interpret=False)
+            out = self._call(kernel, jnp.float64, interpret=True)
+            assert jax.tree.leaves(out)[0].dtype == jnp.float64

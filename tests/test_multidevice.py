@@ -151,7 +151,7 @@ def test_episode_sharded_fleet_matches_unsharded():
         from pathlib import Path
         sys.path.insert(0, str(Path({src!r}).parent))
         import numpy as np
-        from jax.experimental import enable_x64
+        from jax import enable_x64
         from benchmarks.workflow_sim import (
             DEFAULT_ALPHAS, LAMBDA_USD_PER_S, _autoreply_fleet,
             _episode_sharded_shards)
@@ -187,7 +187,7 @@ def test_online_service_row_sharded_matches_unsharded():
     the unsharded executable with identical results."""
     out = run_subprocess("""
         import numpy as np
-        from jax.experimental import enable_x64
+        from jax import enable_x64
         from jax.sharding import Mesh
         from repro.core.online import OnlineDecisionService
         from repro.core.taxonomy import DependencyType

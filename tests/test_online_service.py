@@ -7,7 +7,7 @@ table-batched stages must match their scalar ``calibration`` twins on
 identical logs (posteriors bitwise-f64, promotion/trigger flags exact)."""
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from repro.core.calibration import canary, online_calibration, shadow_mode
 from repro.core.decision import Decision, DecisionInputs, evaluate
@@ -662,6 +662,20 @@ def test_fused_tick_rollout_falls_back_to_xla():
         np.testing.assert_array_equal(da.speculate, db.speculate)
         np.testing.assert_array_equal(
             a.posterior_snapshot(), b.posterior_snapshot())
+
+
+def test_fused_tick_on_tpu_refuses_f64_state(monkeypatch):
+    """Mosaic has no f64: a fused-tick service over an x64 (f64) table
+    on the TPU raises at its first tick instead of quietly dropping back
+    to the XLA executable or casting the table down."""
+    from repro.kernels import ops
+
+    with enable_x64():
+        svc = _service(n_rows=4, use_fused_tick=True)
+        req = _random_requests(np.random.default_rng(2), 4, 4)
+        monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+        with pytest.raises(TypeError, match="float64"):
+            _tick(svc, req)
 
 
 def test_empty_settle_bucket_skipped_at_dispatch():

@@ -99,7 +99,7 @@ def test_serve_mask_per_phase():
 # ---------------------------------------------------------------------------
 # property (a): any breach on an open row demotes within one tick
 # ---------------------------------------------------------------------------
-@settings(max_examples=60)
+@settings(max_examples=60, deadline=None)
 @given(phase=st.integers(min_value=SHADOW, max_value=FULL),
        pb=st.integers(min_value=0, max_value=8),
        tip=st.integers(min_value=0, max_value=40),
@@ -140,7 +140,7 @@ def test_breach_on_expiry_tick_demotes_not_reenters():
 # ---------------------------------------------------------------------------
 # property (b): no re-entry (or serving) before the cooldown expires
 # ---------------------------------------------------------------------------
-@settings(max_examples=40)
+@settings(max_examples=40, deadline=None)
 @given(cooldown=st.integers(min_value=2, max_value=10),
        outcomes=st.integers(min_value=0, max_value=5))
 def test_no_reentry_before_cooldown_expires(cooldown, outcomes):
@@ -166,7 +166,7 @@ def test_no_reentry_before_cooldown_expires(cooldown, outcomes):
 # ---------------------------------------------------------------------------
 # property (c): promotion monotone in observed success rate
 # ---------------------------------------------------------------------------
-@settings(max_examples=60)
+@settings(max_examples=60, deadline=None)
 @given(phase=st.integers(min_value=SHADOW, max_value=ONLINE_CAL),
        n=st.integers(min_value=1, max_value=200),
        s=st.integers(min_value=0, max_value=200),
@@ -192,7 +192,7 @@ def test_promotion_monotone_in_success(phase, n, s, rate, min_obs):
 # ---------------------------------------------------------------------------
 # property (d): phase state survives paged spill/fault-in bitwise
 # ---------------------------------------------------------------------------
-@settings(max_examples=15)
+@settings(max_examples=15, deadline=None)
 @given(seed=st.integers(min_value=0, max_value=10_000))
 def test_roll_state_survives_spill_fault_in_bitwise(seed):
     from repro.core.taxonomy import DependencyType

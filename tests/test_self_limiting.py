@@ -96,7 +96,7 @@ def test_critical_k_grid_matches_scalar_closed_form():
     """The vectorized k_crit grid (batch_decision) agrees with the
     scalar closed form to 1e-9 over the full (edge, alpha) cross (f64 —
     the analytic-curve contract runs at double precision)."""
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     alphas = np.asarray(ALPHAS)
     with enable_x64():

@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 from hypothesis import assume, given, settings
 from hypothesis import strategies as st
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from repro.core import online as online_mod
 from repro.core.calibration import seed_store_from_replay
@@ -298,7 +298,7 @@ def _planted_store(p_star, n_warm, trials, seed):
     return store
 
 
-@settings(max_examples=15)
+@settings(max_examples=15, deadline=None)
 @given(
     p_star=st.floats(min_value=0.15, max_value=0.92),
     n_warm=st.integers(min_value=12, max_value=40),
