@@ -17,7 +17,8 @@ import random
 import string
 import zlib
 
-__all__ = ["given", "settings", "strategies", "HealthCheck", "assume"]
+__all__ = ["given", "settings", "strategies", "HealthCheck", "assume",
+           "example"]
 
 _DEFAULT_MAX_EXAMPLES = 50
 
@@ -100,6 +101,16 @@ def settings(max_examples: int = _DEFAULT_MAX_EXAMPLES, deadline=None, **_kw):
     return deco
 
 
+def example(**kw):
+    """An explicit example, run before any drawn one (above or below
+    ``@given``, as in hypothesis)."""
+    def deco(fn):
+        fn._mini_hyp_examples = [kw] + getattr(fn, "_mini_hyp_examples", [])
+        return fn
+
+    return deco
+
+
 def _boundary_combos(strats):
     """First examples: cartesian boundary combos (capped), like hypothesis's
     preference for edge values."""
@@ -114,6 +125,9 @@ def given(*arg_strats, **kw_strats):
         strats = list(arg_strats) + [kw_strats[k] for k in names]
 
         def wrapper(*outer_args, **outer_kw):
+            for kw in (getattr(wrapper, "_mini_hyp_examples", [])
+                       + getattr(fn, "_mini_hyp_examples", [])):
+                fn(*outer_args, **outer_kw, **kw)
             seed = zlib.crc32(fn.__qualname__.encode())
             rng = random.Random(seed)
             combos = _boundary_combos(strats)

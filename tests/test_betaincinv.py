@@ -9,7 +9,7 @@ absent) at <= 1e-10 relative error, and the scipy-documented special
 values at the edges."""
 import numpy as np
 import pytest
-from hypothesis import given, settings
+from hypothesis import example, given, settings
 from hypothesis import strategies as st
 from jax import enable_x64
 from scipy import stats
@@ -87,6 +87,7 @@ def test_tiny_shape_parameters_deep_tail():
     b=st.floats(min_value=0.05, max_value=80.0),
     q=st.floats(min_value=1e-6, max_value=1.0 - 1e-6),
 )
+@example(a=0.5, b=0.0625, q=0.875)
 def test_property_matches_scipy_and_inverts_cdf(a, b, q):
     """Property sweep: betaincinv is scipy's quantile (<= 1e-10 rel) and a
     true right-inverse of the CDF wherever the draw lands, to the float64

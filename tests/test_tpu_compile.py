@@ -90,14 +90,16 @@ def test_betaincinv_kernel_compiles(one_chip, x64):
 
 
 @x64_modes
-def test_online_tick_kernel_compiles(one_chip, x64):
+@pytest.mark.parametrize("use_lower_bound", [False, True],
+                         ids=["mean", "lower_bound"])
+def test_online_tick_kernel_compiles(one_chip, x64, use_lower_bound):
     from repro.kernels.online_tick import online_tick_kernel_call
 
     s = functools.partial(_spec, one_chip)
     f, i = jnp.float32, jnp.int32
     fn = jax.jit(functools.partial(
-        online_tick_kernel_call, use_lower_bound=False, check_drift=True,
-        block_n=BLOCK_N, interpret=False))
+        online_tick_kernel_call, use_lower_bound=use_lower_bound,
+        check_drift=True, block_n=BLOCK_N, interpret=False))
     with jax.enable_x64(x64):
         compiled = fn.lower(
             s((KERNEL_ROWS, 2), f), s((KERNEL_ROWS, 3), f),
