@@ -51,6 +51,7 @@ from typing import Any, NamedTuple, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .batch_decision import _f, beam_gate, d4_gate
 from .betainc import betaincinv
@@ -112,7 +113,7 @@ def _decode_event_row(v: float) -> Optional[int]:
 
 
 class ServiceState(NamedTuple):
-    """Device-resident service state (a pytree of six packed arrays —
+    """Device-resident service state (a pytree of five packed arrays —
     few, large leaves keep per-tick dispatch overhead low on CPU)."""
 
     post: jax.Array      # (N, 2) posterior alpha/beta rows
@@ -120,7 +121,6 @@ class ServiceState(NamedTuple):
     flags: jax.Array     # (N, 2) int32 [enabled, breach_run]
     roll: jax.Array      # (N, 6) int32 rollout lifecycle columns
     tel: jax.Array       # (R, F) telemetry ring (last R slots, oldest first)
-    counters: jax.Array  # (2,)   int32 [slots ever appended, real rows ever]
 
 
 def _tick_impl(state, zero, row, logrow, reqs, bconf, bwidth, out_row,
@@ -165,7 +165,7 @@ def _tick_impl(state, zero, row, logrow, reqs, bconf, bwidth, out_row,
       4. telemetry — the tick's decision rows (which double as the
          returned decisions) appended to the ring, oldest slots evicted.
     """
-    post, rowcfg, flags, roll, tel, counters = state
+    post, rowcfg, flags, roll, tel = state
 
     # ---- 1. settle this tick's outcomes (exact discount recurrence).
     # ``a*d + zero`` pins round(a*d) (or is the identity fma), so the
@@ -274,11 +274,9 @@ def _tick_impl(state, zero, row, logrow, reqs, bconf, bwidth, out_row,
         tel = rows_out[Bp - R:]
     else:
         tel = jnp.concatenate([tel[Bp:], rows_out], 0)
-    counters = counters + jnp.stack(
-        [jnp.asarray(Bp, jnp.int32), valid.sum(dtype=jnp.int32)])
 
     new_state = ServiceState(post=post, rowcfg=rowcfg, flags=flags,
-                             roll=roll, tel=tel, counters=counters)
+                             roll=roll, tel=tel)
     bools = jnp.stack([flag, enabled_req], 1)
     return new_state, rows_out, bools, triggered, transitions, row_L
 
@@ -314,7 +312,7 @@ def _fused_tick_impl(state, zero, row, logrow, reqs, out_row, out_x,
     # dependency on the kernels package (which imports back into core)
     from ..kernels.online_tick import online_tick_kernel_call
 
-    post, rowcfg, flags, roll, tel, counters = state
+    post, rowcfg, flags, roll, tel = state
     (post, flags, P_used, P_mean, EV, thr, C_spec, L_value,
      flagv, enreqv, trig) = online_tick_kernel_call(
         post, rowcfg, flags, zero, row, reqs, out_row, out_x,
@@ -338,12 +336,9 @@ def _fused_tick_impl(state, zero, row, logrow, reqs, out_row, out_x,
         tel = rows_out[Bp - R:]
     else:
         tel = jnp.concatenate([tel[Bp:], rows_out], 0)
-    counters = counters + jnp.stack(
-        [jnp.asarray(Bp, jnp.int32),
-         (row >= 0).sum(dtype=jnp.int32)])
 
     new_state = ServiceState(post=post, rowcfg=rowcfg, flags=flags,
-                             roll=roll, tel=tel, counters=counters)
+                             roll=roll, tel=tel)
     bools = jnp.stack([flag, enabled_req], 1)
     return (new_state, rows_out, bools, triggered,
             jnp.zeros(0, jnp.int32), jnp.zeros(0, dt))
@@ -391,6 +386,13 @@ class _RowsView:
         return self._store.row_config(i)
 
 
+def _fetch(x) -> np.ndarray:
+    """A tick output on the host: blocks until the device has it, then
+    copies it."""
+    with TraceAnnotation("service.fetch"):
+        return np.asarray(x)
+
+
 @dataclasses.dataclass
 class TickDecisions:
     """One tick's batched answers.  The device outputs are pulled to host
@@ -415,12 +417,12 @@ class TickDecisions:
 
     def _col(self, name: str) -> np.ndarray:
         if "rows" not in self._cache:
-            self._cache["rows"] = np.asarray(self._rows)[: self.batch]
+            self._cache["rows"] = _fetch(self._rows)[: self.batch]
         return self._cache["rows"][:, _COL[name]]
 
     def _bool(self, j: int) -> np.ndarray:
         if "bools" not in self._cache:
-            self._cache["bools"] = np.asarray(self._bools)[: self.batch]
+            self._cache["bools"] = _fetch(self._bools)[: self.batch]
         return self._cache["bools"][:, j]
 
     @property
@@ -475,14 +477,15 @@ class TickDecisions:
     @property
     def drift_triggered(self) -> np.ndarray:
         if "drift" not in self._cache:
-            mask = np.asarray(self._drift)
+            mask = _fetch(self._drift)
             if self._slot_logical is not None:
                 # paged store: compose the per-slot trip mask back into
                 # logical row coordinates (unoccupied slots drop out)
-                out = np.zeros(self._n_logical, bool)
-                sl = self._slot_logical
-                res = sl >= 0
-                out[sl[res]] = mask[: sl.shape[0]][res]
+                with TraceAnnotation("service.drift_mask"):
+                    out = np.zeros(self._n_logical, bool)
+                    sl = self._slot_logical
+                    res = sl >= 0
+                    out[sl[res]] = mask[: sl.shape[0]][res]
                 mask = out
             self._cache["drift"] = mask
         return self._cache["drift"]
@@ -598,12 +601,9 @@ class OnlineDecisionService:
             resident_rows=resident_rows, min_rows=min_rows, mesh=mesh,
             axis_name=axis_name)
         self._tel = None
-        self._counters = None
         self._state_dtype: Optional[str] = None
         self._pending: list[tuple[int, bool]] = []
-        # telemetry totals tracked host-side in unbounded Python ints —
-        # the device-side ServiceState.counters are int32 and would wrap
-        # within hours of sustained serving, silently emptying drains
+        # telemetry totals, tracked host-side in unbounded Python ints
         self._slots_total = 0
         self._rows_total = 0
         self._drained_slots = 0
@@ -717,21 +717,17 @@ class OnlineDecisionService:
                 # case; the f64 -> f32 direction re-rounds, as any dtype
                 # change must
                 tel = np.asarray(self._tel, np.float64)
-                counters = np.asarray(self._counters)
             else:
                 tel = np.zeros((self.telemetry_capacity,
                                 len(TELEMETRY_FIELDS)))
                 tel[:, _COL["row"]] = -1.0    # empty slots drop at drain
-                counters = np.zeros(2, np.int32)
             if self.store.row_sharding is not None:
                 from jax.sharding import NamedSharding, PartitionSpec
 
                 rep = NamedSharding(self.mesh, PartitionSpec())
                 self._tel = jax.device_put(_f(tel), rep)
-                self._counters = jax.device_put(jnp.asarray(counters), rep)
             else:
                 self._tel = _f(tel)
-                self._counters = jnp.asarray(counters)
             self._state_dtype = dtype
             # per-tick constants, rebuilt only here (hot-path dispatch
             # stays free of dtype machinery)
@@ -752,8 +748,7 @@ class OnlineDecisionService:
         self._ensure_ready()
         post, rowcfg, flags, roll = self.store.tables()
         return ServiceState(post=post, rowcfg=rowcfg, flags=flags,
-                            roll=roll, tel=self._tel,
-                            counters=self._counters)
+                            roll=roll, tel=self._tel)
 
     @property
     def state(self) -> ServiceState:
@@ -925,115 +920,122 @@ class OnlineDecisionService:
         are the equivalently packed settled outcomes.  ``bconf`` (Bp, W)
         / ``bwidth`` (Bp,) switch the tick to the beam gate (see
         :meth:`tick`); both pre-packed to the bucket shape."""
-        self._ensure_ready()
-        if (not check_drift and not self._pending and row.shape[0] == 0
-                and (out_row is None or out_row.shape[0] == 0)):
-            # idle tick: nothing to settle, decide or drift-check.  The
-            # jit'd tick would be a provable no-op (the S=0 executable
-            # already skips its scan at trace time) yet still costs ~0.1ms
-            # of dispatch — the deadline batcher fires these constantly,
-            # so skip the XLA call entirely.  State, counters and the
-            # telemetry ring are bitwise what the dispatched no-op leaves.
-            self.idle_ticks_skipped += 1
-            F = len(TELEMETRY_FIELDS)
-            return TickDecisions(
-                batch=0 if batch is None else batch,
-                _rows=np.zeros((0, F), self._np_dtype),
-                _bools=np.zeros((0, 2), bool),
-                _drift=np.zeros(self.store.capacity, bool),
-                _slot_logical=self.store.logical_map(),
-                _n_logical=self.store.n_rows)
-        if self._pending:
-            # outcomes queued via observe() settle first (arrival order),
-            # ahead of this call's packed outcomes
-            pend, self._pending = self._pending, []
-            extra_r = np.fromiter((r for r, _ in pend), np.int32, len(pend))
-            extra_x = np.fromiter((float(s) for _, s in pend),
-                                  self._np_dtype, len(pend))
-            if out_row is None:
+        with TraceAnnotation("service.tick"):
+            self._ensure_ready()
+            if (not check_drift and not self._pending and row.shape[0] == 0
+                    and (out_row is None or out_row.shape[0] == 0)):
+                # idle tick: nothing to settle, decide or drift-check.  The
+                # jit'd tick would be a provable no-op (the S=0 executable
+                # already skips its scan at trace time) yet still costs
+                # ~0.1ms of dispatch — the deadline batcher fires these
+                # constantly, so skip the XLA call entirely.  State and the
+                # telemetry ring are bitwise what the dispatched no-op
+                # leaves.
+                self.idle_ticks_skipped += 1
+                F = len(TELEMETRY_FIELDS)
+                return TickDecisions(
+                    batch=0 if batch is None else batch,
+                    _rows=np.zeros((0, F), self._np_dtype),
+                    _bools=np.zeros((0, 2), bool),
+                    _drift=np.zeros(self.store.capacity, bool),
+                    _slot_logical=self.store.logical_map(),
+                    _n_logical=self.store.n_rows)
+            if self._pending:
+                # outcomes queued via observe() settle first (arrival
+                # order), ahead of this call's packed outcomes
+                pend, self._pending = self._pending, []
+                extra_r = np.fromiter((r for r, _ in pend), np.int32,
+                                      len(pend))
+                extra_x = np.fromiter((float(s) for _, s in pend),
+                                      self._np_dtype, len(pend))
+                if out_row is None:
+                    out_row, out_x = self._empty_out
+                out_row = np.concatenate([extra_r, out_row])
+                out_x = np.concatenate([extra_x, out_x])
+                Sp = _bucket(out_row.shape[0], lo=1)
+                if Sp != out_row.shape[0]:
+                    pad_r = np.full(Sp, -1, np.int32)
+                    pad_r[: out_row.shape[0]] = out_row
+                    pad_x = np.zeros(Sp, self._np_dtype)
+                    pad_x[: out_x.shape[0]] = out_x
+                    out_row, out_x = pad_r, pad_x
+            elif out_row is None:
                 out_row, out_x = self._empty_out
-            out_row = np.concatenate([extra_r, out_row])
-            out_x = np.concatenate([extra_x, out_x])
-            Sp = _bucket(out_row.shape[0], lo=1)
-            if Sp != out_row.shape[0]:
-                pad_r = np.full(Sp, -1, np.int32)
-                pad_r[: out_row.shape[0]] = out_row
-                pad_x = np.zeros(Sp, self._np_dtype)
-                pad_x[: out_x.shape[0]] = out_x
-                out_row, out_x = pad_r, pad_x
-        elif out_row is None:
-            out_row, out_x = self._empty_out
-        if out_row.shape[0] and not (out_row >= 0).any():
-            # all-padding settle bucket: the S>0 executable's settle scan
-            # would be a provable no-op (every lane masked), but S is part
-            # of the trace key, so substituting the S=0 bucket here skips
-            # both the scan trace and its per-tick dispatch cost — bitwise
-            # the same state (mirrors the idle_ticks_skipped fast path)
-            out_row, out_x = self._empty_out
-            self.empty_settles_skipped += 1
-        if self.store.identity:
-            srow, sout = row, out_row
-        else:
-            # paged store: fault every row this tick touches onto the
-            # device (LRU-spilling victims), then run the jit'd tick in
-            # slot coordinates — the executable never sees logical ids,
-            # so unbounded registry growth never retraces it
-            touched = np.concatenate(
-                [row[row >= 0].astype(np.int64),
-                 out_row[out_row >= 0].astype(np.int64)])
-            self.store.ensure_resident(touched)
-            srow = self.store.translate(row)
-            sout = self.store.translate(out_row)
-        state = self._ensure_state()
-        ulb = self.use_lower_bound if use_lower_bound is None else bool(use_lower_bound)
-        rcfg = (self._null_rollcfg if rollout_cfg is None
-                else np.asarray(rollout_cfg, np.int32))
-        use_beam = bconf is not None
-        if use_beam:
-            if bwidth is None:
-                raise ValueError("bconf requires bwidth")
-            if bconf.shape[0] != row.shape[0] or bwidth.shape[0] != row.shape[0]:
-                raise ValueError("bconf/bwidth must match the packed batch")
-        else:
-            bconf, bwidth = self._null_beam
-        # fused Pallas tick: only the settle+gate+drift core is fused, so
-        # rollout / beam ticks always fall back to the XLA executable
-        use_fused = (self.use_fused_tick and not use_rollout
-                     and not use_beam)
-        if use_fused:
-            from ..kernels.ops import _interpret
+            if out_row.shape[0] and not (out_row >= 0).any():
+                # all-padding settle bucket: the S>0 executable's settle
+                # scan would be a provable no-op (every lane masked), but S
+                # is part of the trace key, so substituting the S=0 bucket
+                # here skips both the scan trace and its per-tick dispatch
+                # cost — bitwise the same state (mirrors the
+                # idle_ticks_skipped fast path)
+                out_row, out_x = self._empty_out
+                self.empty_settles_skipped += 1
+            if self.store.identity:
+                srow, sout = row, out_row
+            else:
+                # paged store: fault every row this tick touches onto the
+                # device (LRU-spilling victims), then run the jit'd tick in
+                # slot coordinates — the executable never sees logical ids,
+                # so unbounded registry growth never retraces it
+                touched = np.concatenate(
+                    [row[row >= 0].astype(np.int64),
+                     out_row[out_row >= 0].astype(np.int64)])
+                with TraceAnnotation("store.page_in"):
+                    self.store.ensure_resident(touched)
+                    srow = self.store.translate(row)
+                    sout = self.store.translate(out_row)
+            state = self._ensure_state()
+            ulb = (self.use_lower_bound if use_lower_bound is None
+                   else bool(use_lower_bound))
+            rcfg = (self._null_rollcfg if rollout_cfg is None
+                    else np.asarray(rollout_cfg, np.int32))
+            use_beam = bconf is not None
+            if use_beam:
+                if bwidth is None:
+                    raise ValueError("bconf requires bwidth")
+                if (bconf.shape[0] != row.shape[0]
+                        or bwidth.shape[0] != row.shape[0]):
+                    raise ValueError(
+                        "bconf/bwidth must match the packed batch")
+            else:
+                bconf, bwidth = self._null_beam
+            # fused Pallas tick: only the settle+gate+drift core is fused,
+            # so rollout / beam ticks always fall back to the XLA executable
+            use_fused = (self.use_fused_tick and not use_rollout
+                         and not use_beam)
+            if use_fused:
+                from ..kernels.ops import _interpret
 
-            fn = _fused_tick_donated if self.donate else _fused_tick
-            new_state, rows_out, bools, drift, transitions, row_L = fn(
-                state, self._zero, srow, row, reqs, sout, out_x,
-                self._cn, use_lower_bound=ulb, check_drift=check_drift,
-                block_n=self.fused_block_n, interpret=_interpret(),
-            )
-        else:
-            fn = _tick_donated if self.donate else _tick
-            new_state, rows_out, bools, drift, transitions, row_L = fn(
-                state, self._zero, srow, row, reqs, bconf, bwidth, sout,
-                out_x, self._cn, rcfg, use_lower_bound=ulb,
-                check_drift=check_drift, use_rollout=bool(use_rollout),
-                use_beam=use_beam,
-            )
-        self.store.adopt(new_state.post, new_state.rowcfg, new_state.flags,
-                         new_state.roll)
-        self._tel = new_state.tel
-        self._counters = new_state.counters
-        n_real = int((row >= 0).sum())
-        self._slots_total += int(row.shape[0])
-        self._rows_total += n_real
-        # sentinels are tail-only by the packing convention, so the real
-        # batch defaults to the valid count — never report padding slots
-        # as decisions
-        return TickDecisions(
-            batch=n_real if batch is None else batch,
-            _rows=rows_out, _bools=bools, _drift=drift,
-            _slot_logical=self.store.logical_map(),
-            _n_logical=self.store.n_rows,
-            _transitions=transitions if use_rollout else None,
-            _row_L=row_L if use_rollout else None)
+                fn = _fused_tick_donated if self.donate else _fused_tick
+                new_state, rows_out, bools, drift, transitions, row_L = fn(
+                    state, self._zero, srow, row, reqs, sout, out_x,
+                    self._cn, use_lower_bound=ulb, check_drift=check_drift,
+                    block_n=self.fused_block_n, interpret=_interpret(),
+                )
+            else:
+                fn = _tick_donated if self.donate else _tick
+                new_state, rows_out, bools, drift, transitions, row_L = fn(
+                    state, self._zero, srow, row, reqs, bconf, bwidth, sout,
+                    out_x, self._cn, rcfg, use_lower_bound=ulb,
+                    check_drift=check_drift, use_rollout=bool(use_rollout),
+                    use_beam=use_beam,
+                )
+            self.store.adopt(new_state.post, new_state.rowcfg,
+                             new_state.flags, new_state.roll)
+            self._tel = new_state.tel
+            n_real = int((row >= 0).sum())
+            self._slots_total += int(row.shape[0])
+            self._rows_total += n_real
+            # sentinels are tail-only by the packing convention, so the
+            # real batch defaults to the valid count — never report padding
+            # slots as decisions
+            return TickDecisions(
+                batch=n_real if batch is None else batch,
+                _rows=rows_out, _bools=bools, _drift=drift,
+                _slot_logical=self.store.logical_map(),
+                _n_logical=self.store.n_rows,
+                _transitions=transitions if use_rollout else None,
+                _row_L=row_L if use_rollout else None)
 
     def apply_outcomes(
         self, outcomes: Optional[Sequence[tuple[int, bool]]] = None
@@ -1178,8 +1180,6 @@ class OnlineDecisionService:
         window (see :meth:`log_events`) are decoded into ``events``."""
         self._ensure_ready()
         tel = np.asarray(self._tel)
-        # host-side unbounded totals (the device counters are int32 and
-        # may wrap on long-lived services; they remain for in-graph use)
         slots, total_rows = self._slots_total, self._rows_total
         total_events = self._events_total
         R = tel.shape[0]

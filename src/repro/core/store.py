@@ -46,6 +46,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .posterior import BetaPosterior
 from .taxonomy import DEFAULT_N0, DependencyType, prior_params
@@ -471,7 +472,8 @@ class PosteriorStore:
         (slot == id).  Snapshotted per tick for drift translation."""
         if self._identity:
             return None
-        return self._logical_at.copy()
+        with TraceAnnotation("store.slot_map"):
+            return self._logical_at.copy()
 
     def _device_put(self, post_np, cfg_np, flags_np, roll_np):
         self.row_sharding = None

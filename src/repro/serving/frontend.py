@@ -44,6 +44,7 @@ import time
 from typing import Any, Callable, Optional, Sequence
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core.decision import Decision, DecisionInputs, evaluate
 from ..core.posterior import BetaPosterior
@@ -527,7 +528,7 @@ class ServingFrontend:
     def _loop(self) -> None:
         cfg = self.config
         while not self._stop.is_set():
-            with self._cv:
+            with TraceAnnotation("frontend.wait_batch"), self._cv:
                 while not self._pending and not self._stop.is_set():
                     self._cv.wait(timeout=0.1)
                 if self._stop.is_set() and not self._pending:
@@ -557,7 +558,8 @@ class ServingFrontend:
             self.stats["full_ticks"] += 1
         else:
             self.stats["deadline_ticks"] += 1
-        self._flush(batch)
+        with TraceAnnotation("frontend.flush"):
+            self._flush(batch)
         return len(batch)
 
     def _pack(self, batch: Sequence[FrontendTicket]):
@@ -596,8 +598,9 @@ class ServingFrontend:
 
     def _flush(self, batch: Sequence[FrontendTicket]) -> None:
         cfg = self.config
-        row, reqs, B = self._pack(batch)
-        out_row, out_x, settles = self._pack_settles(reqs.dtype)
+        with TraceAnnotation("frontend.pack"):
+            row, reqs, B = self._pack(batch)
+            out_row, out_x, settles = self._pack_settles(reqs.dtype)
         tick = lambda: self.service.tick_packed(  # noqa: E731
             row, reqs, batch=B, out_row=out_row, out_x=out_x,
             check_drift=cfg.check_drift)
@@ -641,49 +644,53 @@ class ServingFrontend:
             return
 
         # healthy tick: distribute answers, close half-open circuits
-        for key in {t.request.key for t in batch}:
-            self.breaker.record_success(key)
-        # in-graph kill-switch breaches fold into the breaker as trips
-        # (once per breach onset, not re-tripped every tick while down).
-        # ``drift_triggered`` is a pulse — the in-graph run resets after
-        # firing — so the breached set must accumulate across ticks, and
-        # a row only leaves it once it is observed serving enabled again
-        # (host re-enable / rollout re-entry).  That way a *second*
-        # breach after a recovery re-emits a fresh trip instead of being
-        # swallowed as a duplicate.
-        tripped = {int(r) for r in np.flatnonzero(decisions.drift_triggered)}
-        if self._breached:
-            en = decisions.enabled
-            for i in range(B):
-                r = int(row[i])
-                if (r >= 0 and r not in tripped and bool(en[i])
-                        and r in self._breached):
-                    self._breached.discard(r)
-        for r in sorted(tripped - self._breached):
-            tenant, edge = self.service.row_key(r)
-            self.breaker.trip((tenant, edge))
-            self._emit_raw(tenant, edge, r, "drift_trip", 0.0,
-                           detail="kill-switch breach")
-        self._breached |= tripped
-        spec = decisions.speculate
-        for i, t in enumerate(batch):
-            self.stats["service"] += 1
-            res = FrontendResult(
-                decision=(Decision.SPECULATE if bool(spec[i])
-                          else Decision.WAIT),
-                source="service",
-                EV_usd=float(decisions.EV_usd[i]),
-                threshold_usd=float(decisions.threshold_usd[i]),
-                C_spec_usd=float(decisions.C_spec_usd[i]),
-                L_value_usd=float(decisions.L_value_usd[i]),
-                P_used=float(decisions.P_used[i]),
-            )
-            if res.decision is not Decision.SPECULATE:
-                t.release()
-            t._resolve(res)
+        with TraceAnnotation("frontend.answer"):
+            for key in {t.request.key for t in batch}:
+                self.breaker.record_success(key)
+            # in-graph kill-switch breaches fold into the breaker as
+            # trips (once per breach onset, not re-tripped every tick
+            # while down).  ``drift_triggered`` is a pulse — the in-graph
+            # run resets after firing — so the breached set must
+            # accumulate across ticks, and a row only leaves it once it is
+            # observed serving enabled again (host re-enable / rollout
+            # re-entry).  That way a *second* breach after a recovery
+            # re-emits a fresh trip instead of being swallowed as a
+            # duplicate.
+            tripped = {int(r) for r in
+                       np.flatnonzero(decisions.drift_triggered)}
+            if self._breached:
+                en = decisions.enabled
+                for i in range(B):
+                    r = int(row[i])
+                    if (r >= 0 and r not in tripped and bool(en[i])
+                            and r in self._breached):
+                        self._breached.discard(r)
+            for r in sorted(tripped - self._breached):
+                tenant, edge = self.service.row_key(r)
+                self.breaker.trip((tenant, edge))
+                self._emit_raw(tenant, edge, r, "drift_trip", 0.0,
+                               detail="kill-switch breach")
+            self._breached |= tripped
+            spec = decisions.speculate
+            for i, t in enumerate(batch):
+                self.stats["service"] += 1
+                res = FrontendResult(
+                    decision=(Decision.SPECULATE if bool(spec[i])
+                              else Decision.WAIT),
+                    source="service",
+                    EV_usd=float(decisions.EV_usd[i]),
+                    threshold_usd=float(decisions.threshold_usd[i]),
+                    C_spec_usd=float(decisions.C_spec_usd[i]),
+                    L_value_usd=float(decisions.L_value_usd[i]),
+                    P_used=float(decisions.P_used[i]),
+                )
+                if res.decision is not Decision.SPECULATE:
+                    t.release()
+                t._resolve(res)
         self._ticks_since_snapshot += 1
         if self._ticks_since_snapshot >= cfg.snapshot_refresh_ticks:
-            self._refresh_snapshot()
+            with TraceAnnotation("frontend.refresh_mirror"):
+                self._refresh_snapshot()
 
     def _refresh_snapshot(self) -> None:
         try:

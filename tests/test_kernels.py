@@ -338,8 +338,7 @@ class TestOnlineTickKernel:
         state = ol.ServiceState(
             post=post, rowcfg=rowcfg, flags=flags,
             roll=jnp.ones((post.shape[0], 6), jnp.int32),
-            tel=jnp.zeros((32, len(ol.TELEMETRY_FIELDS)), post.dtype),
-            counters=jnp.zeros(2, jnp.int32))
+            tel=jnp.zeros((32, len(ol.TELEMETRY_FIELDS)), post.dtype))
         # the JITTED tick, exactly as the service dispatches it: calling
         # _tick_impl eagerly would bake `zero` into the settle scan as a
         # constant, XLA would fold the `+ zero` pin away and contract

@@ -68,7 +68,7 @@ def test_service_tick_compiles(one_chip, use_lower_bound):
     f, i = jnp.float32, jnp.int32
     state = ServiceState(post=s((ROWS, 2), f), rowcfg=s((ROWS, 3), f),
                          flags=s((ROWS, 2), i), roll=s((ROWS, 6), i),
-                         tel=s((4096, 10), f), counters=s((2,), i))
+                         tel=s((4096, 10), f))
     compiled = _tick.lower(
         state, s((), f), s((BATCH,), i), s((BATCH,), i), s((BATCH, 7), f),
         s((0, 1), f), s((0,), i), s((SETTLES,), i), s((SETTLES,), f),
