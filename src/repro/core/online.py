@@ -52,6 +52,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
+from jax.scipy.special import betainc
 
 from .batch_decision import _f, beam_gate, d4_gate
 from .betainc import betaincinv
@@ -123,6 +124,25 @@ class ServiceState(NamedTuple):
     tel: jax.Array       # (R, F) telemetry ring (last R slots, oldest first)
 
 
+def _breached(a, b, gamma, floor, touched):
+    """Trigger 2's breach test, ``betaincinv(a, b, gamma) < floor``, on
+    the touched lanes.  ``I_x(a, b)`` increases strictly in ``x`` on
+    (0, 1), so the quantile lies under the floor exactly when the CDF at
+    the floor exceeds ``gamma``: one ``betainc`` instead of an inversion.
+    Clipping the floor to [0, 1] keeps the edges: a floor <= 0 (or an
+    unfloored row's -inf) never breaches, one >= 1 always does.
+
+    Untouched lanes are pinned to an inert Beta(1, 1) at 0.5, as the
+    §7.5 gate pins its padding: an empty slot's zeros (NaN in
+    ``betainc``) or a slow lane cannot keep the all-lanes continued
+    fraction iterating, which would move the touched lanes' bits."""
+    one = jnp.ones_like(a)
+    x = jnp.clip(floor, 0.0, 1.0)
+    cdf = betainc(jnp.where(touched, a, one), jnp.where(touched, b, one),
+                  jnp.where(touched, x, 0.5 * one))
+    return touched & (cdf > gamma)
+
+
 def _tick_impl(state, zero, row, logrow, reqs, bconf, bwidth, out_row,
                out_x, consecutive_n, rollcfg, use_lower_bound, check_drift,
                use_rollout, use_beam):
@@ -155,7 +175,9 @@ def _tick_impl(state, zero, row, logrow, reqs, bconf, bwidth, out_row,
          contraction-pinned :func:`batch_decision.d4_gate`, optionally on
          the §7.5 lower bound (one vmapped ``betaincinv``);
       3. drift/kill-switch — one ``check_credible_bound_batch``-semantics
-         breach step per *touched* row (post-settlement posteriors);
+         breach step per *touched* row (post-settlement posteriors),
+         tested as the Beta CDF at the floor (:func:`_breached`) rather
+         than by inverting the quantile;
       3b. rollout lifecycle (``use_rollout``) — the staged-rollout state
          machine advances on the same touched mask: serving is gated by
          the *pre-tick* phase (``rollout.rollout_allow``), demotion by
@@ -227,8 +249,8 @@ def _tick_impl(state, zero, row, logrow, reqs, bconf, bwidth, out_row,
             valid.astype(jnp.int32)) > 0
     if check_drift:
         run = flags[:, 1]
-        P_low = betaincinv(post[:, 0], post[:, 1], rowcfg[:, 0])
-        breached = touched & (P_low < rowcfg[:, 2])
+        breached = _breached(post[:, 0], post[:, 1], rowcfg[:, 0],
+                             rowcfg[:, 2], touched)
         run = jnp.where(touched, jnp.where(breached, run + 1, 0), run)
         triggered = touched & (run >= consecutive_n)
         enabled = (flags[:, 0] > 0) & ~triggered

@@ -5,16 +5,22 @@ contraction-pinned D4 gate), posterior settlement must be bitwise the
 ``DriftMonitor.check_credible_bound`` step-for-step, and the §12.2–12.4
 table-batched stages must match their scalar ``calibration`` twins on
 identical logs (posteriors bitwise-f64, promotion/trigger flags exact)."""
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax import enable_x64
 
+from repro.core import online
+from repro.core.betainc import betaincinv
 from repro.core.calibration import canary, online_calibration, shadow_mode
 from repro.core.decision import Decision, DecisionInputs, evaluate
 from repro.core.drift import DriftMonitor
 from repro.core.online import (
     OnlineDecisionService,
+    ServiceState,
     TELEMETRY_FIELDS,
+    _breached,
     canary_batch,
     online_calibration_batch,
     shadow_mode_batch,
@@ -285,6 +291,160 @@ def test_drift_matches_scalar_monitor_and_gates_serving():
                           latency_s=10.0, input_tokens=1, output_tokens=1,
                           input_price=1e-9, output_price=1e-9)
         assert res.decision is Decision.WAIT and res.EV_usd > res.threshold_usd
+
+
+def test_drift_trips_match_quantile_replay_over_many_ticks():
+    """f32 table, many rows (some floored, with spare zero slots in the
+    table): over 24 ticks of repeated and partial batches with settles,
+    breach runs, enable bits and trigger pulses equal a NumPy replay of
+    the quantile rule ``beta.ppf(gamma, a, b) < floor`` on the
+    post-settlement posteriors, tick by tick."""
+    from scipy import stats
+
+    n_rows, cn = 40, 3
+    svc = OnlineDecisionService(credible_consecutive_n=cn)
+    gamma = np.where(np.arange(n_rows) % 4 == 0, 0.05, 0.1)
+    gamma[5::6] = 0.5
+    floor = np.full(n_rows, -np.inf)
+    for i in range(n_rows):
+        floored = i % 2 == 0
+        # floor (1 - fa) * C / (L + C): 0.5 for fa = 0, 0.25 for fa = 0.5
+        fa = 0.0 if i % 4 == 0 else 0.5
+        svc.register_edge(
+            ("u", f"v{i}"), dep_type=DependencyType.ROUTER_K_WAY,
+            k=2 + i % 3, discount=(0.9 if i % 3 == 0 else 1.0),
+            gamma=float(gamma[i]), floor_alpha=fa,
+            floor_C_spec_usd=(0.01 if floored else None),
+            floor_L_value_usd=(0.01 if floored else None))
+        if floored:
+            floor[i] = (1.0 - fa) * 0.5
+    assert np.asarray(svc.posterior_snapshot()).dtype == np.float32
+    rng = np.random.default_rng(23)
+    p_true = rng.uniform(0.05, 0.95, n_rows)
+    run = np.zeros(n_rows, np.int64)
+    enabled = np.ones(n_rows, bool)
+    trips, breaches, margin = 0, 0, np.inf
+    for t in range(24):
+        B = int(rng.integers(1, 30))
+        # repeats inside a batch, and a hot set of rows touched often
+        rows = np.where(rng.random(B) < 0.6, rng.integers(0, 8, B),
+                        rng.integers(0, n_rows, B))
+        settle = rng.integers(0, n_rows, int(rng.integers(0, 20)))
+        outcomes = [(int(r), bool(rng.random() < p_true[r])) for r in settle]
+        req = _random_requests(rng, B, n_rows)
+        req["rows"] = rows
+        d = _tick(svc, req, outcomes=outcomes, check_drift=True)
+        post = np.asarray(svc.posterior_snapshot(), np.float64)[:n_rows]
+        touched = np.zeros(n_rows, bool)
+        touched[rows] = True
+        ppf = stats.beta.ppf(gamma, post[:, 0], post[:, 1])
+        fin = touched & np.isfinite(floor)
+        margin = min(margin, np.abs(ppf - floor)[fin].min(initial=np.inf))
+        breached = touched & (ppf < floor)
+        run = np.where(touched, np.where(breached, run + 1, 0), run)
+        triggered = touched & (run >= cn)
+        enabled &= ~triggered
+        run = np.where(triggered, 0, run)
+        trips += int(triggered.sum())
+        breaches += int(breached.sum())
+        np.testing.assert_array_equal(d.drift_triggered[:n_rows], triggered)
+        np.testing.assert_array_equal(svc.breach_runs()[:n_rows], run)
+        np.testing.assert_array_equal(svc.enabled_snapshot()[:n_rows],
+                                      enabled)
+    # the replay met no tie, and the rule both breached and tripped
+    assert margin > 1e-4
+    assert trips >= 2 and breaches > trips
+
+
+# the kill switch's breach test against the quantile it replaces: a grid
+# of small discounted and long-history posteriors (a or b under 1
+# included), floors at 0 and below, inside (0, 1), and at 1 and above
+_AB = (0.3, 0.7, 1.0, 2.5, 6.0, 150.0, 900.0, 2500.0, 4000.0)
+_FLOORS = (-np.inf, -0.5, 0.0, 0.02, 0.1, 0.25, 0.4, 0.5, 0.6, 0.75, 0.9,
+           0.98, 1.0, 1.5)
+
+
+def _breach_grid(dtype):
+    return tuple(x.ravel().astype(dtype) for x in
+                 np.meshgrid(_AB, _AB, _FLOORS, indexing="ij"))
+
+
+@pytest.mark.parametrize("gamma", [0.05, 0.1, 0.5])
+@pytest.mark.parametrize("x64", [False, True], ids=["f32", "f64"])
+def test_breach_cdf_test_equals_quantile_rule(x64, gamma):
+    """``I_floor(a, b) > gamma`` breaches exactly where the quantile rule
+    ``betaincinv(a, b, gamma) < floor`` does, at f32 and f64, away from
+    ties (lanes within 1e-5 of the floor by SciPy's quantile)."""
+    from scipy import stats
+
+    dtype = np.float64 if x64 else np.float32
+    with enable_x64(x64):
+        a, b, f = _breach_grid(dtype)
+        g = np.full_like(a, gamma)
+        touched = np.ones(a.shape, bool)
+        got = np.asarray(jax.jit(_breached)(a, b, g, f, touched))
+        want = np.asarray(betaincinv(a, b, g) < f)
+    ppf = stats.beta.ppf(gamma, a.astype(np.float64), b.astype(np.float64))
+    far = ~(np.abs(ppf - f) <= 1e-5)
+    assert far.sum() > 0.95 * far.size
+    np.testing.assert_array_equal(got[far], want[far])
+    np.testing.assert_array_equal(got[far], (ppf < f)[far])
+    # the edges: a floor at or under 0 never breaches, one at or over 1
+    # always does, and the interior has both answers
+    assert not got[f <= 0].any() and got[f >= 1].all()
+    inner = (f > 0) & (f < 1)
+    assert got[inner].any() and not got[inner].all()
+
+
+@pytest.mark.parametrize("x64", [False, True], ids=["f32", "f64"])
+def test_breach_untouched_and_empty_slots_are_inert(x64):
+    """Untouched lanes never breach, whatever they hold (an empty slot's
+    zeros, NaN, a long history), and do not change the touched lanes'
+    answers."""
+    dtype = np.float64 if x64 else np.float32
+    with enable_x64(x64):
+        a, b, f = _breach_grid(dtype)
+        g = np.full_like(a, 0.1)
+        alone = np.asarray(_breached(a, b, g, f, np.ones(a.shape, bool)))
+        n = a.size
+        junk_a = np.resize(np.array([0.0, np.nan, 4000.0, 0.3], dtype), n)
+        junk_b = np.resize(np.array([0.0, 2.0, 0.3, np.nan], dtype), n)
+        # touched lanes interleaved with untouched junk lanes
+        mixed = lambda x, j: np.stack([x, j], 1).ravel()  # noqa: E731
+        touched = np.tile([True, False], n)
+        got = np.asarray(_breached(
+            mixed(a, junk_a), mixed(b, junk_b), np.repeat(g, 2),
+            mixed(f, np.resize(np.array([0.5, 1.5], dtype), n)), touched))
+    assert not got[~touched].any()
+    np.testing.assert_array_equal(got[touched], alone)
+
+
+def test_drift_step_adds_one_loop_to_the_tick():
+    """The drift step is one CDF evaluation: the lowered tick with the
+    drift check on holds at most one ``while`` more than with it off (the
+    CDF's continued fraction), so the quantile's Halley loop around it
+    cannot come back to this path.  Counted after lowering, where the
+    CDF's loop is visible; in the jaxpr it is one primitive."""
+    s = jax.ShapeDtypeStruct
+    f, i = jnp.float32, jnp.int32
+    rows, batch, settles = 1024, 64, 64
+    state = ServiceState(post=s((rows, 2), f), rowcfg=s((rows, 3), f),
+                         flags=s((rows, 2), i), roll=s((rows, 6), i),
+                         tel=s((256, 10), f))
+    args = (state, s((), f), s((batch,), i), s((batch,), i),
+            s((batch, 7), f), s((0, 1), f), s((0,), i), s((settles,), i),
+            s((settles,), f), s((), i), s((9,), i))
+
+    def loops(check_drift):
+        lowered = online._tick.trace(*args, use_lower_bound=False,
+                                     check_drift=check_drift,
+                                     use_rollout=False,
+                                     use_beam=False).lower()
+        return lowered.as_text().count("stablehlo.while")
+
+    off, on = loops(False), loops(True)
+    assert off == 1                       # the settle scan
+    assert off < on <= off + 1
 
 
 # ---------------------------------------------------------------------------
